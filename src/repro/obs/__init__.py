@@ -1,4 +1,5 @@
-"""Production metrics plane: registry, zero-sync sampler, exporters.
+"""Production metrics plane: registry, zero-sync sampler, exporters; the
+engine's named spans on the profiler timeline are in ``repro.obs.spans``.
 
     from repro.obs import MetricsRegistry, MetricsSampler
     reg = MetricsRegistry()
@@ -11,8 +12,7 @@ convention and the zero-overhead contract the ``hotpath_micro --check``
 ``bench_metrics`` gate enforces.
 """
 from .exporters import (TimeSeriesLog, parse_prometheus_text,
-                        request_trace_events, to_prometheus_text,
-                        write_chrome_trace, write_json_snapshot,
+                        to_prometheus_text, write_json_snapshot,
                         write_prometheus)
 from .registry import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                        HistogramValue, MetricsRegistry, Snapshot)
@@ -23,5 +23,4 @@ __all__ = [
     "Histogram", "HistogramValue", "DEFAULT_BUCKETS", "SYNC_KINDS",
     "publish_engine", "to_prometheus_text", "parse_prometheus_text",
     "write_prometheus", "write_json_snapshot", "TimeSeriesLog",
-    "request_trace_events", "write_chrome_trace",
 ]

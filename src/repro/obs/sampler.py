@@ -159,6 +159,10 @@ def publish_engine(engine, reg: MetricsRegistry,
       engine.n_chunk_calls)
     c("engine_prefill_compiles_total", "distinct prefill trace shapes",
       engine.n_prefill_compiles)
+    c("engine_run_ahead_iters_total", "undrained decode iterations at "
+      "each async decode dispatch, summed", engine.ahead_iters_sum)
+    c("engine_run_ahead_samples_total", "async decode dispatches the "
+      "run-ahead sum is taken over", engine.n_ahead_samples)
     c("engine_kv_migrations_total", "KV page images by direction",
       engine.n_kv_exports, direction="export")
     c("engine_kv_migrations_total", "KV page images by direction",

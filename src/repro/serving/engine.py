@@ -90,6 +90,7 @@ from repro.core.scheduler import SchedulerConfig, make_econoserve
 from repro.models import model
 from repro.models.attention import POS_INVALID
 from repro.models.config import ATTN, ModelConfig
+from repro.obs.spans import span
 
 from .sampling import SamplingParams, sample_in_graph, sample_per_request
 
@@ -206,6 +207,14 @@ class GenRequest:
     output: List[int] = field(default_factory=list)
     t_submit: float = 0.0
     t_done: Optional[float] = None
+    # lifecycle stamps on the caller's clock (the ``now`` of submit/step):
+    # the scheduler first sees the request (later than t_submit only when
+    # an open megastep window held it), its first prompt grant, and the
+    # step that sampled its first token; the last two are copied from the
+    # scheduler's Request when the request completes
+    t_admitted: Optional[float] = None
+    t_scheduled: Optional[float] = None
+    t_first_sampled: Optional[float] = None
     # --- fault tolerance / SLO enforcement -----------------------------
     deadline: float = float("inf")   # absolute (iteration-clock) deadline
     status: Optional[str] = None     # terminal: completed | aborted | shed
@@ -417,6 +426,11 @@ class ServingEngine:
             maxlen=max(1, self.ecfg.readback_lag))
         self.n_tokens_drained = 0    # tokens materialized through the ring
         self.decode_iters = 0
+        # run-ahead: at each async decode dispatch, the decode iterations
+        # already dispatched whose token rows are not drained yet (summed),
+        # and the number of such dispatches
+        self.ahead_iters_sum = 0
+        self.n_ahead_samples = 0
         # metrics plane (repro.obs): an attached MetricsSampler is invoked
         # at the end of every step — host-side reads only, no device ops,
         # so metrics-on stays bitwise-identical with zero added syncs
@@ -696,6 +710,7 @@ class ServingEngine:
             self._arrivals.append((r, now))
         else:
             self.scheduler.on_arrival(r, now)
+            req.t_admitted = now
         return req.rid
 
     def validate(self, req: GenRequest) -> None:
@@ -1634,6 +1649,10 @@ class ServingEngine:
         if ab != self._active_bytes:
             self._active_bytes = ab
             self._active_dev = self._place(active)
+        # every ring entry is one dispatched iteration's token row, and no
+        # window is open here, so the ring holds all undrained iterations
+        self.ahead_iters_sum += len(self._pending_drain)
+        self.n_ahead_samples += 1
         K = self.scheduler.decode_horizon(plan, self._mega_max)
         if K > 1:
             # under pressure (waiters certified KVC-blocked) an EOS
@@ -1749,7 +1768,8 @@ class ServingEngine:
         uniq: Dict[int, jax.Array] = {}
         for toks, _, _ in batch:
             uniq.setdefault(id(toks), toks)
-        mats = jax.device_get(list(uniq.values()))
+        with span("engine.drain_wait"):
+            mats = jax.device_get(list(uniq.values()))
         mat_of = dict(zip(uniq.keys(), mats))
         for toks, row, mapping in batch:
             arr = mat_of[id(toks)]
@@ -1768,22 +1788,25 @@ class ServingEngine:
             # a fused window just drained: apply the aborts it deferred
             # (freed slots/KVC are then visible to the injects/arrivals),
             # then deliver arrivals and peer KV injections
-            for rid, t_ab, reason in self._pending_aborts:
-                self._apply_abort(rid, t_ab, reason)
-            self._pending_aborts.clear()
-            for payload, t_in in self._pending_injects:
-                self._apply_inject(payload, t_in)
-            self._pending_injects.clear()
-            for r, t_arr in self._arrivals:
-                self.scheduler.on_arrival(r, t_arr)
-            self._arrivals.clear()
+            with span("engine.admit"):
+                for rid, t_ab, reason in self._pending_aborts:
+                    self._apply_abort(rid, t_ab, reason)
+                self._pending_aborts.clear()
+                for payload, t_in in self._pending_injects:
+                    self._apply_inject(payload, t_in)
+                self._pending_injects.clear()
+                for r, t_arr in self._arrivals:
+                    self.scheduler.on_arrival(r, t_arr)
+                    self.requests[r.rid].t_admitted = now
+                self._arrivals.clear()
         if self._mega_left == 0 and self._pending_squeeze:
             kvc = self.scheduler.kvc
             kvc.shrink(int(kvc.capacity_tokens * self._pending_squeeze))
             self._pending_squeeze = 0.0
         if self.guard is not None and self._mega_left == 0:
             self._guard_step(now)
-        plan = self.scheduler.form_batch(now)
+        with span("engine.form_batch"):
+            plan = self.scheduler.form_batch(now)
         if self.scheduler.infeasible_shed:
             # rung 4: the scheduler cancelled requests a squeeze made
             # permanently inadmissible *here* — surface each as a
@@ -1833,11 +1856,27 @@ class ServingEngine:
             self._drain_tokens(force=True)
         if missing:
             missing = self._swap_in(missing, now)
-        self._run_prefill(plan.prompt_items, now, missing=missing)
-        if self._async:
-            self._run_decode_async(plan, now)
-        else:
-            self._run_decode(plan.decode_reqs, now)
+        with span("engine.prefill"):
+            self._run_prefill(plan.prompt_items, now, missing=missing)
+        # the decode span carries the run-ahead counters as they stood
+        # before this step, so a trace reads their change over a window
+        with span("engine.decode", ahead_iters=self.ahead_iters_sum,
+                  ahead_samples=self.n_ahead_samples):
+            if self._async:
+                self._run_decode_async(plan, now)
+            else:
+                self._run_decode(plan.decode_reqs, now)
+        with span("engine.finish"):
+            done = self._finish(now)
+        if self.metrics is not None:
+            self.metrics.on_step(self, now)
+        return len(done)
+
+    def _finish(self, now: float) -> List[Request]:
+        """Close the iteration in the scheduler, then settle what it
+        decided: terminal state of completed requests, slots of completed
+        and preempted ones, and the forced drain their outputs need.
+        Returns the requests completed in this iteration."""
         before = len(self.scheduler.completed)
         self.scheduler.finish_iteration(now)
         done = self.scheduler.completed[before:]
@@ -1852,6 +1891,8 @@ class ServingEngine:
                 self.n_dup_completions += 1
             else:
                 g.t_done = r.t_complete
+                g.t_scheduled = r.t_start_exec
+                g.t_first_sampled = r.t_first_token
                 g.status = "completed"
             slot = self.slot_of.pop(r.rid, None)
             if slot is not None:
@@ -1877,9 +1918,7 @@ class ServingEngine:
             # context from g.output at the next prefill
             self.sync_counts["flush"] += 1
             self._drain_tokens(force=True)
-        if self.metrics is not None:
-            self.metrics.on_step(self, now)
-        return len(done)
+        return done
 
     def flush(self) -> None:
         """Force-drain the token readback ring so every request's

@@ -9,7 +9,6 @@ Covers the PR-9 acceptance checklist:
   * snapshot immutability (frozen at capture, unaffected by later
     publishes);
   * Prometheus text round-trip and JSON exports;
-  * Chrome trace_event span construction from request timestamps;
   * enqueue-time drain classification is deterministic across repeated
     runs of the same stream (the PR-8 race this PR fixes);
   * a sampler-attached engine produces bitwise-identical tokens and
@@ -23,8 +22,8 @@ import pytest
 
 from repro.obs import (DEFAULT_BUCKETS, MetricsRegistry, MetricsSampler,
                        Snapshot, TimeSeriesLog, parse_prometheus_text,
-                       publish_engine, request_trace_events,
-                       to_prometheus_text, write_json_snapshot)
+                       publish_engine, to_prometheus_text,
+                       write_json_snapshot)
 
 
 # --------------------------------------------------------------------- #
@@ -205,28 +204,6 @@ def test_json_snapshot_and_timeseries(tmp_path):
     q = tmp_path / "ts.json"
     log.write(str(q))
     assert json.loads(q.read_text())["series"]["a"]["v"] == [1.0, 2.0]
-
-
-def test_chrome_trace_events():
-    from repro.core.request import Request, State
-
-    r = Request(rid=0, prompt_len=8, true_rl=4, arrival=1.0,
-                slo_deadline=50.0)
-    r.set_state(State.RUNNING_PT, 2.0)
-    r.t_start_exec = 2.0
-    r.t_first_token = 3.0
-    r.generated = 4
-    r.set_state(State.COMPLETED, 6.0)
-    events = request_trace_events([r])
-    phases = [(e["name"], e["ph"]) for e in events]
-    assert ("queued", "X") in phases
-    assert ("prefill", "X") in phases
-    assert ("decode", "X") in phases
-    for e in events:
-        if e["ph"] == "X":
-            assert e["dur"] >= 0 and e["tid"] == 0
-    js = json.dumps(events)        # must be JSON-serializable as-is
-    assert "traceEvents" not in js  # list form, loadable by about:tracing
 
 
 # --------------------------------------------------------------------- #
