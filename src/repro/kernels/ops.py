@@ -72,27 +72,31 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "pages_per_step"))
-def decode_attention(q, cache_k, cache_v, context_lens, *,
+def decode_attention(q, cache_k, cache_v, context_lens, layer=0, *,
                      softcap: Optional[float] = None,
                      pages_per_step: int = 8):
     """Decode attention over a contiguous per-request cache row.
 
-    q (B,H,hd); cache_k/v (B,C,K,hd); context_lens (B,) — number of valid
+    q (B,H,hd); cache_k/v (B,C,K,hd), or a layer stack (L,B,C,K,hd) of
+    which layer ``layer`` is read; context_lens (B,) — number of valid
     slots (for ring buffers every written slot is valid; softmax is
     permutation-invariant so slot order does not matter).
 
-    Implemented by viewing each row as pages of the paged kernel.
+    Implemented by viewing each row as pages of the paged kernel. A stack
+    is viewed as pages whole and the layer picked by the block table, so
+    no layer is sliced out of it.
     """
-    B, C, K, hd = cache_k.shape
+    B, C, K, hd = cache_k.shape[-4:]
     for ps in (128, 64, 32, 16, 8):
         if C % ps == 0:
             break
     else:
         ps = C
     mp = C // ps
-    kp = cache_k.reshape(B * mp, ps, K, hd)
-    vp = cache_v.reshape(B * mp, ps, K, hd)
-    bt = (jnp.arange(B)[:, None] * mp + jnp.arange(mp)[None, :]).astype(jnp.int32)
+    kp = cache_k.reshape(-1, ps, K, hd)
+    vp = cache_v.reshape(-1, ps, K, hd)
+    bt = (layer * (B * mp) + jnp.arange(B)[:, None] * mp
+          + jnp.arange(mp)[None, :]).astype(jnp.int32)
     return _paged_pallas(q, kp, vp, bt, context_lens.astype(jnp.int32),
                          softcap=softcap, pages_per_step=pages_per_step,
                          interpret=_interpret())

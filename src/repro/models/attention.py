@@ -266,25 +266,31 @@ def attn_prefill(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
 
 
 def attn_decode(p, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
-                cache_k: jax.Array, cache_v: jax.Array,
-                *, kv_heads: Optional[int] = None, impl: str = "xla"
+                cache_k: jax.Array, cache_v: jax.Array, layer,
+                active: jax.Array, *, kv_heads: Optional[int] = None,
+                impl: str = "xla"
                 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """One-token decode.
+    """One-token decode against layer ``layer`` of a stacked cache.
 
-    x (B,1,d); pos (B,) absolute position of the new token;
-    cache_k/v (B, C, K, hd) where C = full context or sliding window size.
-    Returns (y (B,1,d), updated cache).
+    x (B,1,d); pos (B,) absolute position of the new token; active (B,)
+    bool; cache_k/v (L, B, C, K, hd) where C = full context or sliding
+    window size. The new K/V row is written in place at
+    ``[layer, b, slot_b]`` for active rows only (inactive rows target an
+    out-of-range slot and are dropped), then the query attends over
+    ``cache[layer]`` (the Pallas kernel reads that layer's pages out of
+    the stack itself). Returns (y (B,1,d), updated stacked cache).
     """
     B = x.shape[0]
-    C = cache_k.shape[1]
+    C = cache_k.shape[2]
     nkv = kv_heads or cfg.num_kv_heads
     q, k, v = _project_qkv(p, cfg, x, pos[:, None], nkv)
 
     windowed = cfg.sliding_window is not None and C == cfg.sliding_window
     slot = jnp.where(windowed, pos % C, jnp.minimum(pos, C - 1))
     bidx = jnp.arange(B)
-    cache_k = cache_k.at[bidx, slot].set(k[:, 0])
-    cache_v = cache_v.at[bidx, slot].set(v[:, 0])
+    wslot = jnp.where(active, slot, C)
+    cache_k = cache_k.at[layer, bidx, wslot].set(k[:, 0], mode="drop")
+    cache_v = cache_v.at[layer, bidx, wslot].set(v[:, 0], mode="drop")
 
     idx = jnp.arange(C)[None, :]                     # (1,C) slot index
     if windowed:
@@ -300,8 +306,10 @@ def attn_decode(p, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
         # ring-buffer slot order does not matter — a count suffices
         n_valid = jnp.minimum(pos + 1, C) if windowed else pos + 1
         out = ops.decode_attention(q[:, 0], cache_k, cache_v, n_valid,
-                                   softcap=cfg.attn_logit_softcap)[:, None]
+                                   layer, softcap=cfg.attn_logit_softcap
+                                   )[:, None]
     else:
-        out = _sdpa(q, cache_k, cache_v, mask[:, None, :], cfg)
+        out = _sdpa(q, cache_k[layer], cache_v[layer], mask[:, None, :],
+                    cfg)
     y = jnp.einsum("bsh,hd->bsd", out.reshape(B, 1, -1), p["wo"])
     return y, (cache_k, cache_v)

@@ -182,11 +182,15 @@ def _apply_block_prefill(cfg: ModelConfig, kind: str, p: Params, x, positions,
 
 
 def _apply_block_decode(cfg: ModelConfig, kind: str, p: Params, x, pos,
-                        cache, impl: str):
+                        cache, impl: str, layer=None, active=None):
+    """Attention takes the kind's whole stacked cache and writes layer
+    ``layer``'s row in place (active rows only); recurrent kinds take and
+    return their own layer's state."""
     if kind == ATTN:
         h = rms_norm(x, p["norm1"], cfg.rms_eps)
         y, (ck, cv) = attention.attn_decode(_sub(p, "attn/"), cfg, h, pos,
-                                            cache["k"], cache["v"], impl=impl)
+                                            cache["k"], cache["v"], layer,
+                                            active, impl=impl)
         x = x + y
         h = rms_norm(x, p["norm2"], cfg.rms_eps)
         if cfg.is_moe:
@@ -227,17 +231,19 @@ def _shared_attn_prefill(cfg, params, x, positions, impl, segment_ids=None,
     return x + mlp.mlp_apply(_sub(p, "mlp/"), h), (k, v)
 
 
-def _shared_attn_decode(cfg, params, x, pos, ck, cv, impl):
+def _shared_attn_decode(cfg, params, x, pos, active, cache, i, impl):
+    """Invocation ``i`` of the shared block against the stacked shared
+    cache, written in place like ``attn_decode``."""
     scfg = cfg if not cfg.shared_attn_kv_heads else cfg.with_(
         num_kv_heads=cfg.shared_attn_kv_heads)
     p = _sub(params, "shared/")
     h = rms_norm(x, p["norm1"], cfg.rms_eps)
     y, (ck, cv) = attention.attn_decode(
-        _sub(p, "attn/"), scfg, h, pos, ck, cv,
+        _sub(p, "attn/"), scfg, h, pos, cache["k"], cache["v"], i, active,
         kv_heads=scfg.num_kv_heads, impl=impl)
     x = x + y
     h = rms_norm(x, p["norm2"], cfg.rms_eps)
-    return x + mlp.mlp_apply(_sub(p, "mlp/"), h), (ck, cv)
+    return x + mlp.mlp_apply(_sub(p, "mlp/"), h), {"k": ck, "v": cv}
 
 
 # --------------------------------------------------------------------------- #
@@ -266,6 +272,7 @@ def logits_fn(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
 def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
                positions: jax.Array, impl: str,
                decode: bool = False, pos=None, caches: Optional[Cache] = None,
+               active=None,
                segment_ids: Optional[jax.Array] = None,
                prefix_caches: Optional[Cache] = None, prefix_len=None,
                prefix_positions=None, prefix_segment_ids=None):
@@ -277,8 +284,16 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
     multi-request form replaces the scalar ``prefix_len`` with per-slot
     ``prefix_positions``/``prefix_segment_ids``; recurrent kinds instead
     receive their carried state snapshots through the same pytree.
+
+    Decode carries each attention kind's whole stacked cache through the
+    layer loop (never as scan ``xs``/``ys``, which would slice out and
+    write back every layer's slab): each layer writes its new row in place
+    and attends over its own layer of the carried cache. Recurrent kinds
+    advance only the ``active`` rows of their (small) states.
     """
     aux_total = jnp.zeros((), jnp.float32)
+    if decode:
+        caches = dict(caches)
     new_caches: Dict[str, List] = {k: [] for k in cfg.block_kinds()}
     shared_caches: List = []
     every = cfg.shared_attention_every
@@ -304,7 +319,24 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
             # decode hot path, and hybrid patterns produce many length-1
             # segments; the unrolled call is mathematically identical) ---
             x = _constrain_acts(x)
-            if decode:
+            if decode and kind == ATTN:
+                def body_a(carry, lp):
+                    xc, kv, layer = carry
+                    y, kv = _apply_block_decode(cfg, kind, lp, xc, pos, kv,
+                                                impl, layer, active)
+                    return (y, kv, layer + 1), None
+
+                body = jax.checkpoint(body_a) if cfg.remat else body_a
+                start = off + sub_start
+                if run == 1:
+                    (x, caches[kind], _), _ = body(
+                        (x, caches[kind], start),
+                        jax.tree.map(lambda a: a[0], seg_params))
+                else:
+                    (x, caches[kind], _), _ = jax.lax.scan(
+                        body, (x, caches[kind], jnp.int32(start)),
+                        seg_params)
+            elif decode:
                 cache_off = _cache_offset(new_caches[kind])
                 seg_cache = jax.tree.map(
                     lambda a: jax.lax.slice_in_dim(a, cache_off,
@@ -316,6 +348,12 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
                     lp, lc = xs
                     y, c2 = _apply_block_decode(cfg, kind, lp, xc, pos, lc,
                                                 impl)
+                    # an idle row keeps its state: a spurious h <- f(h, x)
+                    # advance would corrupt it
+                    c2 = jax.tree.map(
+                        lambda old, new: jnp.where(active.reshape(
+                            (-1,) + (1,) * (new.ndim - 1)), new, old),
+                        lc, c2)
                     return y, c2
 
                 body = jax.checkpoint(body_d) if cfg.remat else body_d
@@ -379,11 +417,9 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
             sub_start += run
             if every and n_done % every == 0 and shared_i < num_shared_invocations(cfg):
                 if decode:
-                    ck = caches["shared"]["k"][shared_i]
-                    cv = caches["shared"]["v"][shared_i]
-                    x, (ck, cv) = _shared_attn_decode(cfg, params, x, pos,
-                                                      ck, cv, impl)
-                    shared_caches.append((ck, cv))
+                    x, caches["shared"] = _shared_attn_decode(
+                        cfg, params, x, pos, active, caches["shared"],
+                        shared_i, impl)
                 else:
                     sprefix = None
                     if prefix_caches is not None:
@@ -404,7 +440,11 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array,
             out_caches[kind] = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=0), *lst) \
                 if len(lst) > 1 else lst[0]
-    if shared_caches:
+    if decode:
+        for kind in (ATTN, "shared"):
+            if kind in caches:
+                out_caches[kind] = caches[kind]
+    elif shared_caches:
         out_caches["shared"] = {
             "k": jnp.stack([c[0] for c in shared_caches]),
             "v": jnp.stack([c[1] for c in shared_caches]),
@@ -508,10 +548,19 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 pos: jax.Array, caches: Cache, impl: str = "xla"
                 ) -> Tuple[jax.Array, Cache]:
-    """tokens (B,1); pos (B,) absolute positions. Returns (logits (B,V), caches)."""
+    """tokens (B,1); pos (B,) absolute positions. Returns (logits (B,V), caches).
+
+    A row with a negative ``pos`` is idle: its K/V rows and recurrent
+    states are left as they are and its logits are meaningless. Each
+    other row writes one K/V row per attention layer, in place in the
+    (donated) stacked cache.
+    """
+    active = pos >= 0
+    pos = jnp.maximum(pos, 0)
     x = jnp.take(params["embed/tok"], tokens, axis=0).astype(cfg.dtype)
     x, new_caches, _ = _run_stack(cfg, params, x, None, impl,
-                                  decode=True, pos=pos, caches=caches)
+                                  decode=True, pos=pos, caches=caches,
+                                  active=active)
     return logits_fn(cfg, params, x[:, 0]), new_caches
 
 

@@ -437,36 +437,22 @@ class ServingEngine:
         self.metrics = None
 
         def _decode_fn(p, tok, pos, caches, active):
-            """Legacy sync decode step with inactive slots masked out of the
-            cache update. Attention writes to idle slots were merely
-            wasteful (idempotent); recurrent states (SSM/xLSTM) would be
-            silently corrupted by spurious h <- f(h, x) advances."""
-            logits, new_caches = model.decode_step(cfg, p, tok, pos, caches,
-                                                   impl=impl)
+            """Forward pass of the active slots. Inactive rows get a negative
+            position, so the model leaves their K/V rows and recurrent
+            states (SSM/xLSTM) as they are."""
+            return model.decode_step(cfg, p, tok, jnp.where(active, pos, -1),
+                                     caches, impl=impl)
 
-            def sel(old, new):
-                m = active.reshape((1, -1) + (1,) * (new.ndim - 2))
-                return jnp.where(m, new, old)
-
-            return logits, jax.tree.map(sel, caches, new_caches)
-
-        self._decode = jax.jit(_decode_fn)
+        self._decode = jax.jit(_decode_fn, donate_argnums=(3,))
 
         def _one_iter(p, caches, st, active, need_sample, need_topk):
-            """One fused async decode iteration: forward pass, masked cache
-            update, in-graph RNG split + sampling, EOS check and pos
-            advance in one traced body. Shared verbatim by the single-step
-            program and the megastep while_loop so both produce bitwise-
-            identical results."""
-            toks = st["last_tok"][:, None]
-            logits, new_caches = model.decode_step(cfg, p, toks, st["pos"],
-                                                   caches, impl=impl)
-
-            def sel(old, new):
-                m = active.reshape((1, -1) + (1,) * (new.ndim - 2))
-                return jnp.where(m, new, old)
-
-            new_caches = jax.tree.map(sel, caches, new_caches)
+            """One fused async decode iteration: forward pass writing the
+            active slots' K/V rows in place, in-graph RNG split + sampling,
+            EOS check and pos advance in one traced body. Shared verbatim
+            by the single-step program and the megastep while_loop so both
+            produce bitwise-identical results."""
+            logits, new_caches = _decode_fn(
+                p, st["last_tok"][:, None], st["pos"], caches, active)
             temps = jnp.where(active, st["temps"], 0.0)
             top_ks = jnp.where(active, st["top_ks"], 0)
             key, sk = jax.random.split(st["key"])

@@ -150,3 +150,24 @@ def test_packed_seed_ring_cache_matches_padded_path():
         outs.append([g.output for g in reqs])
     assert outs[0] == outs[1]
     assert all(len(o) == 6 for o in outs[0])
+
+
+@pytest.mark.parametrize("program", ["fused", "mega"])
+def test_decode_program_makes_no_cache_sized_temp(program):
+    """The decode iteration writes one K/V row per active slot in place in
+    the donated cache: its scratch stays below the stacked cache's bytes.
+    (A whole-cache select, or slicing every layer's slab out of the stack
+    and writing it back, needs at least one more cache's worth.)"""
+    cfg = get_config("qwen3_8b").reduced(layers=4, d_model=128).with_(
+        dtype="float32", param_dtype="float32")
+    eng = ServingEngine(cfg, max_batch=4, capacity=256, rl_accuracy=1.0)
+    cache_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.caches))
+    active = jnp.array([True, False, True, True])
+    if program == "fused":
+        lowered = eng._fused.lower(eng.params, eng.caches, eng._dev, active,
+                                   False, False)
+    else:
+        lowered = eng._mega.lower(eng.params, eng.caches, eng._dev, active,
+                                  jnp.int32(4), False, False, False)
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes, (temp, cache_bytes)

@@ -289,6 +289,22 @@ def test_decode_attention_contiguous_wrapper():
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
+def test_decode_attention_reads_one_layer_of_a_stack():
+    """A stacked (L,B,C,K,hd) cache with a layer index reads exactly what
+    the layer's own (B,C,K,hd) slab gives."""
+    from repro.kernels import ops
+    L, B, C, K, hd, H = 3, 2, 64, 2, 64, 4
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, H, hd))
+    ck = jax.random.normal(ks[1], (L, B, C, K, hd))
+    cv = jax.random.normal(ks[2], (L, B, C, K, hd))
+    ctx = jnp.array([17, 64], jnp.int32)
+    for layer in range(L):
+        out = ops.decode_attention(q, ck, cv, ctx, jnp.int32(layer))
+        want = ops.decode_attention(q, ck[layer], cv[layer], ctx)
+        assert (out == want).all(), layer
+
+
 def test_kv_page_append_roundtrip():
     from repro.kernels.ref import kv_page_append
     B, page, K, hd, MP = 2, 8, 2, 16, 3

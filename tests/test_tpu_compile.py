@@ -99,3 +99,32 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_step_copies_no_cache_slab_for_v5e(impl, one_chip,
+                                                  no_persistent_cache):
+    """The decode step at Qwen3-8B's widths over the engine's 16 x 2048
+    cache, two layers: the new K/V rows are written in place and each
+    layer is read where it lies, so the scratch stays below one layer's
+    K and V slabs (a slab sliced out, or viewed in another layout for the
+    kernel, would need that much at least)."""
+    from repro.configs import get_config
+    from repro.models import model
+    cfg = get_config("qwen3_8b").with_(num_layers=2)
+    B, C = 16, 2048
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, model.abstract(cfg))
+    caches = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init_cache(cfg, B, C)))
+    tokens = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)
+
+    def step(p, tok, pos, caches):
+        return model.decode_step(cfg, p, tok, pos, caches, impl=impl)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, pos, caches).compile()
+    slab = B * C * K * HD * 2 * 2                 # one layer's K and V, bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < slab
